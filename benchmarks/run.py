@@ -18,6 +18,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from benchmarks import kernel_micro, paper_figs, roofline_table, serving_bench
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     suites = []
     for mod in (paper_figs, kernel_micro, roofline_table, serving_bench):

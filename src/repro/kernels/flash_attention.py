@@ -168,7 +168,7 @@ def _packed_prefill_kernel(sot_ref, st_ref, off_ref, len_ref, bt_ref,
         @pl.when((page < n_pages) & (s0 < ring) & (off > 0))
         def _run():
             q = q_ref[0]                                     # [bq, D]
-            k = kp_ref[0, :, 0, :]                           # [P, D]
+            k = kp_ref[0]                                    # [P, D]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bq, P]
@@ -183,7 +183,7 @@ def _packed_prefill_kernel(sot_ref, st_ref, off_ref, len_ref, bt_ref,
                     & (prev_pos >= 0) & (prev_pos <= q_pos))
             if window > 0:
                 mask &= (q_pos - prev_pos) < window
-            accum(jnp.where(mask, s, NEG_INF), vp_ref[0, :, 0, :])
+            accum(jnp.where(mask, s, NEG_INF), vp_ref[0])
 
     @pl.when(j >= nw)
     def _stream():
@@ -264,11 +264,18 @@ def packed_prefill_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
     kh = k_new.swapaxes(0, 1)              # [Hkv, T, D]
     vh = v_new.swapaxes(0, 1)
 
+    # the pool as [n_pages, P, Hkv*D]: a (1, P, D) block at column block
+    # h // G is one kv head's page, and its last two dims tile as Mosaic
+    # requires ((P, D) = (16, 128) at the paged widths) where a
+    # (1, P, 1, D) block of the 4-D pool does not
+    kp = k_pages.reshape(n_pages, P, Hkv * D)
+    vp = v_pages.reshape(n_pages, P, Hkv * D)
+
     def page_map(h, i, j, sot, st, off, ln, btr):
         # clamp the sentinel: the fetched page is ignored (pl.when masks
         # the whole step) but the DMA address must stay in bounds
         pg = btr[sot[i], jnp.minimum(j, W - 1)]
-        return (jnp.minimum(pg, n_pages - 1), 0, h // G, 0)
+        return (jnp.minimum(pg, n_pages - 1), 0, h // G)
 
     def stream_map(h, i, j, sot, st, off, ln, btr):
         return (h // G, jnp.maximum(j - W, 0), 0)
@@ -281,8 +288,8 @@ def packed_prefill_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
                          lambda h, i, j, sot, st, off, ln, btr: (h, i, 0)),
             pl.BlockSpec((1, bq, D), stream_map),
             pl.BlockSpec((1, bq, D), stream_map),
-            pl.BlockSpec((1, P, 1, D), page_map),
-            pl.BlockSpec((1, P, 1, D), page_map),
+            pl.BlockSpec((1, P, D), page_map),
+            pl.BlockSpec((1, P, D), page_map),
         ],
         out_specs=pl.BlockSpec(
             (1, bq, D), lambda h, i, j, sot, st, off, ln, btr: (h, i, 0)),
@@ -300,5 +307,5 @@ def packed_prefill_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
         out_shape=jax.ShapeDtypeStruct((Hkv * G, T, D), q.dtype),
         interpret=interpret,
     )(seg_of_tile, starts, jnp.asarray(seg_offsets, jnp.int32),
-      jnp.asarray(seg_lengths, jnp.int32), bt, qh, kh, vh, k_pages, v_pages)
+      jnp.asarray(seg_lengths, jnp.int32), bt, qh, kh, vh, kp, vp)
     return out.swapaxes(0, 1)              # [T, H, D]

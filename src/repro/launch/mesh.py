@@ -20,7 +20,14 @@ from typing import List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto(axes: Tuple[str, ...]) -> Tuple[AxisType, ...]:
+    """Auto axes: the sharding rules (``distributed/policy.constrain``
+    under ``with mesh:``) leave propagation to the compiler, which
+    ``jax.make_mesh``'s default Explicit axes refuse."""
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,17 +36,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=_auto(axes))
     # single-pod mesh on a 512-device host platform: take the first pod
     assert len(devices) >= n, (
         f"need {n} devices, have {len(devices)} — dryrun.py must set "
         "XLA_FLAGS=--xla_force_host_platform_device_count=512 first")
-    return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return Mesh(np.asarray(devices[:n]).reshape(shape), axes,
+                axis_types=_auto(axes))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh over however many devices this process sees (tests)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(data: Optional[int] = None, model: Optional[int] = None):
@@ -52,7 +60,8 @@ def make_host_mesh(data: Optional[int] = None, model: Optional[int] = None):
     data = data or n // (model or 1)
     model = model or n // data
     assert data * model == n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    axes = ("data", "model")
+    return jax.make_mesh((data, model), axes, axis_types=_auto(axes))
 
 
 def phase_device_groups(devices: Optional[List] = None

@@ -220,6 +220,7 @@ def main(argv=None) -> int:
 
     import jax
     from repro.configs.base import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models.transformer import init_params
     from repro.serving.engine import ServeConfig, ServingEngine
     from repro.serving.metrics import SLO
@@ -227,6 +228,7 @@ def main(argv=None) -> int:
     from repro.serving.scheduler import AdmissionConfig, PhaseAwareConfig
     from repro.serving.tracing import Tracer
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -374,7 +376,7 @@ def main(argv=None) -> int:
           f"{np.median(decode_ticks)*1e3 if decode_ticks else 0:.1f}ms  "
           f"host-transfers={engine.host_transfers}")
     kv = engine.kv_bytes()
-    mode = (f"paged[{args.n_pages}x{args.page_size},{args.kv_dtype}]"
+    mode = (f"paged[{args.n_pages}x{args.page_size},{engine.pool.page_dtype}]"
             if args.paged else f"dense[max_len={args.max_len}]")
     print(f"kv={mode} weights={args.weights_dtype} "
           f"reserved={kv['reserved']/1e6:.2f}MB "

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -512,22 +512,56 @@ def make_packed_segs(starts, offsets, lengths, slots, T: int) -> PackedSegs:
                       starts, offsets, lengths, slots)
 
 
-def _use_packed_kernel(pack_align: int, T: int, softcap, window) -> bool:
-    """Pallas packed-prefill kernel dispatch: TPU backend, no softcap, a
-    tile-aligned stream (segment starts aligned to pack_align >= 128 so a
-    bq-tile never straddles two segments), and a trace-time window."""
-    import jax as _jax
-    if _jax.default_backend() != "tpu":
-        return False
-    if softcap and softcap > 0.0:
-        return False
-    if pack_align < 128 or T % pack_align != 0:
-        return False
-    try:
-        int(window)
-    except Exception:
-        return False
-    return True
+# The packed-prefill kernel's query tile: segment starts must be aligned
+# to it so a tile never straddles two segments.
+PACKED_KERNEL_TILE = 128
+
+# Trace-time route counters, in the pattern of layers.gemv_route_count:
+# each traced packed-prefill / paged-decode attention call adds one to the
+# route it took ("kernel" = the Pallas kernel, "jnp" = the gathered
+# jax.numpy path), so a program that gave way to jnp on a TPU shows it.
+_routes: Dict[str, int] = {}
+
+
+def _note_route(name: str) -> None:
+    _routes[name] = _routes.get(name, 0) + 1
+
+
+def attention_route_counts() -> Dict[str, int]:
+    """{"packed_prefill.kernel"|"packed_prefill.jnp"|"paged_decode.kernel"
+    |"paged_decode.jnp": traced calls} since the last reset."""
+    return dict(_routes)
+
+
+def reset_attention_route_counts() -> None:
+    _routes.clear()
+
+
+def packed_kernel_align(softcap, quantized: bool) -> int:
+    """Segment alignment the packed-prefill Pallas kernel needs where it
+    runs (TPU backend, no softcap, float KV), else 0.  The serving engine
+    aligns its packed streams to this, so on a TPU the default paged path
+    reaches the kernel whatever ``PhaseAwareConfig.pack_align`` says."""
+    if jax.default_backend() != "tpu" or (softcap and softcap > 0.0):
+        return 0
+    return 0 if quantized else PACKED_KERNEL_TILE
+
+
+def _use_packed_kernel(pack_align: int, T: int, softcap, window,
+                       quantized: bool = False) -> bool:
+    """Pallas packed-prefill kernel dispatch: ``packed_kernel_align``
+    holds, the stream's segments are tile-aligned (a bq-tile never
+    straddles two segments), and the window is a trace-time constant.
+    Counts the route taken."""
+    tile = packed_kernel_align(softcap, quantized)
+    use = bool(tile) and pack_align % tile == 0 and T % pack_align == 0
+    if use:
+        try:
+            int(window)
+        except Exception:
+            use = False
+    _note_route("packed_prefill.kernel" if use else "packed_prefill.jnp")
+    return use
 
 
 def _packed_attention_jax(q, k, v, prev_k, prev_v, prev_pos, seg, *,
@@ -671,7 +705,7 @@ def attn_chunk_packed_paged(params, x, seg: PackedSegs, cache, block_table,
     bt_rows = jnp.asarray(block_table, jnp.int32)[
         jnp.clip(seg.slots, 0, B - 1)]                           # [N, W]
 
-    if not quant and _use_packed_kernel(pack_align, T, softcap, window):
+    if _use_packed_kernel(pack_align, T, softcap, window, quantized=quant):
         from repro.kernels import ops as _kops
         ctx = _kops.packed_prefill_attention(
             q, k, v, cache["k"], cache["v"], bt_rows,
@@ -938,6 +972,7 @@ def attn_decode_paged(params, x, cache, block_table, pos, *, n_heads,
     if softcap and softcap > 0.0:
         # the kernel has no softcap path; gather a dense view and reuse the
         # reference math (softcapped GQA decode is not on the paper's path)
+        _note_route("paged_decode.jnp")
         gk = ck[jnp.clip(bt, 0, n_pages - 1)].reshape(
             B, -1, n_kv_heads, d_head)
         gv = cv[jnp.clip(bt, 0, n_pages - 1)].reshape(
@@ -955,6 +990,7 @@ def attn_decode_paged(params, x, cache, block_table, pos, *, n_heads,
         ctx = jnp.einsum("bhgs,bshd->bhgd", p.astype(gv.dtype), gv,
                          preferred_element_type=jnp.float32)
     else:
+        _note_route("paged_decode.kernel")
         ctx = _kops.paged_decode_attention(
             q.reshape(B, n_heads, d_head), ck, cv, bt, lengths)
     ctx = ctx.reshape(B, 1, n_heads * d_head).astype(x.dtype)
@@ -996,6 +1032,7 @@ def attn_decode_q8_paged(params, x, cache, block_table, pos, *, n_heads,
     cvs = cache["v_scale"].at[w_page, off].set(v_s[:, 0], mode="drop")
 
     # gather the sequence's pages (int8 + scales) through the block table
+    _note_route("paged_decode.jnp")
     rows = jnp.clip(bt, 0, n_pages - 1)
     S = bt.shape[1] * P
     gk = ck[rows].reshape(B, S, Hkv, d_head)       # int8
@@ -1050,6 +1087,7 @@ def attn_decode_q4_paged(params, x, cache, block_table, pos, *, n_heads,
 
     lengths = jnp.minimum(pos + 1, R)
     if softcap and softcap > 0.0:
+        _note_route("paged_decode.jnp")
         rows = jnp.clip(bt, 0, n_pages - 1)
         S = bt.shape[1] * P
         gk = dequantize(unpack_int4(ck[rows]), cks[rows]).reshape(
@@ -1068,6 +1106,7 @@ def attn_decode_q4_paged(params, x, cache, block_table, pos, *, n_heads,
         ctx = jnp.einsum("bhgs,bshd->bhgd", p, gv,
                          preferred_element_type=jnp.float32)
     else:
+        _note_route("paged_decode.kernel")
         ctx = _kops.paged_decode_attention_q4(
             q.reshape(B, n_heads, d_head), ck, cks, cv, cvs, bt, lengths)
     ctx = ctx.reshape(B, 1, n_heads * d_head).astype(x.dtype)

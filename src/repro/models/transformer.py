@@ -106,10 +106,11 @@ def build_plan(cfg: ModelConfig) -> List[RunSpec]:
 
 
 def _stack(key, n: int, init_fn):
-    """Stack n independently-initialized param trees along axis 0."""
-    keys = jax.random.split(key, n)
-    trees = [init_fn(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs, 0), *trees)
+    """Stack n independently-initialized param trees along axis 0.  One
+    vmapped init instead of n stacked ones: the same values, and a traced
+    init that does not grow with depth (a jitted full-width init otherwise
+    spends minutes compiling)."""
+    return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
 def padded_vocab(cfg: ModelConfig) -> int:

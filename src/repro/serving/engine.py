@@ -126,6 +126,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.models.attention import packed_kernel_align
 from repro.models.transformer import (
     forward,
     forward_chunk,
@@ -397,6 +398,15 @@ class ServingEngine:
         # writes at (slot, offset)) and a flat single-codebook stream
         self._packed = (sc.packed_prefill and self.chunked
                         and cfg.n_codebooks <= 1)
+        # segment alignment of the packed stream: where the Pallas packed
+        # prefill kernel runs on the page pool (TPU, float KV, no softcap)
+        # it is the kernel's query tile, so the default paged path reaches
+        # the kernel.  The dense arena keeps the jnp path: its whole ring
+        # would be one kernel block, unchecked against VMEM.
+        tile = packed_kernel_align(
+            cfg.attn.logit_softcap, quantized=sc.kv_dtype != "f32") \
+            if sc.paged and not cfg.mla.enabled else 0
+        self._pack_align = max(sc.phase.pack_align, tile)
         self.prefill_launches = 0        # prefill phase-program calls
         self.prefill_rows_executed = 0   # token rows computed (incl. pad)
         # the EXECUTOR owns the jitted program table, compile accounting
@@ -490,7 +500,7 @@ class ServingEngine:
         one compiled shape per bucketed T."""
         logits, new_cache = forward_chunk_packed(
             params, self.cfg, tokens, starts, offsets, lengths, slots,
-            cache, pack_align=self.sc.phase.pack_align)
+            cache, pack_align=self._pack_align)
         return self._sample(logits, temps, top_ks, top_ps, seeds, counters,
                             all_greedy), new_cache
 
@@ -502,7 +512,7 @@ class ServingEngine:
         logits, new_cache = forward_chunk_packed(
             params, self.cfg, tokens, starts, offsets, lengths, slots,
             cache, block_tables=block_tables,
-            pack_align=self.sc.phase.pack_align)
+            pack_align=self._pack_align)
         return self._sample(logits, temps, top_ks, top_ps, seeds, counters,
                             all_greedy), new_cache
 
@@ -1430,7 +1440,7 @@ class ServingEngine:
         the returned tokens samples chunk i, exactly like the padded
         batch."""
         packed = pack_chunks([(req.req_id, take) for req, take in chunks],
-                             align=self.sc.phase.pack_align)
+                             align=self._pack_align)
         T = packed.length
         Nb = self.sc.max_batch
         tokens = np.zeros((T,), np.int32)

@@ -19,11 +19,17 @@ kind).  Two placements:
   handoff the engine reports the request's freshly-filled KV pages via
   ``record_handoff``; the executor accounts them as pages/bytes crossing
   the 2.5D interposer link — batched per tick (one link transaction per
-  tick, however many requests finished prefilling in it).  On a
-  single-device host both groups resolve to the same device, so greedy
-  streams are bit-identical colocated vs disaggregated BY CONSTRUCTION
-  — the programs, batches, and sampling are the same; only placement
-  and ownership accounting differ.
+  tick, however many requests finished prefilling in it).  Each phase
+  program runs on the FIRST device of its group, with its inputs placed
+  there explicitly: the parameters are copied to each phase device once,
+  and any other input that lives elsewhere (the whole KV pool, after the
+  other phase last wrote it) is moved and counted in ``moved_bytes``.
+  The pool is one array, so a phase switch moves all of it, not only the
+  handed-off pages.  On a single-device host both groups resolve to the
+  same device and nothing moves, so greedy streams are bit-identical
+  colocated vs disaggregated BY CONSTRUCTION — the programs, batches,
+  and sampling are the same; only placement and ownership accounting
+  differ.
 
 Compile counting also lives here: every phase call notes its (group,
 kind, bucketed shape, all_greedy) key and a first sighting counts as a
@@ -125,7 +131,7 @@ class Executor:
                          static_argnums=(static_arg,))
             dev = self.device_for(kind)
             if dev is not None:
-                fn = _pin(fn, dev)
+                fn = self._placed(fn, dev)
             self.programs[key] = fn
         return self.programs[key]
 
@@ -181,6 +187,9 @@ class DisaggregatedExecutor(Executor):
     quantity under study — still runs for real)."""
 
     migrates_kv = True
+    # device-to-device bytes the placement moved (parameter copies + pool
+    # moves + small per-call inputs made on the default device)
+    moved_bytes = counter_attr("serving_placement_moved_bytes_total")
 
     def __init__(self, impls: Dict[str, Callable], *, mesh=None,
                  metrics: Optional[MetricsRegistry] = None,
@@ -188,11 +197,47 @@ class DisaggregatedExecutor(Executor):
         super().__init__(impls, mesh=mesh, metrics=metrics)
         groups = devices if devices is not None else phase_device_groups()
         self.prefill_devices, self.decode_devices = groups
+        self.moved_bytes = 0
+        self.tick_moved_bytes = 0
+        self.max_tick_moved_bytes = 0
+        # device -> (params object, its copy on that device)
+        self._params_on: Dict[Any, Tuple[Any, Any]] = {}
 
     def device_for(self, kind: str):
         group = (self.decode_devices if self.phase_of(kind) == "decode"
                  else self.prefill_devices)
         return group[0] if group else None
+
+    def _put(self, tree, dev):
+        """``tree`` with every array leaf that is not on ``dev`` alone
+        moved there (committed), its bytes counted."""
+        def put(x):
+            if isinstance(x, jax.Array) and x.devices() != {dev}:
+                self.tick_moved_bytes += x.nbytes
+                self.moved_bytes += x.nbytes
+                self.max_tick_moved_bytes = max(self.max_tick_moved_bytes,
+                                                self.tick_moved_bytes)
+                return jax.device_put(x, dev)
+            return x
+        return jax.tree.map(put, tree)
+
+    def _placed(self, fn: Callable, dev) -> Callable:
+        """Run ``fn(params, *rest)`` on ``dev`` with its inputs placed
+        there explicitly.  Left to ``jax.default_device``, uncommitted
+        inputs would follow the default device silently; here the
+        parameters are copied to ``dev`` once and reused, and every other
+        input that lives elsewhere is moved and counted."""
+        def run(params, *rest):
+            held = self._params_on.get(dev)
+            if held is None or held[0] is not params:
+                held = (params, self._put(params, dev))
+                self._params_on[dev] = held
+            return fn(held[1], *self._put(rest, dev))
+        return run
+
+    def begin_tick(self) -> None:
+        super().begin_tick()
+        self.tick_moved_bytes = 0
 
     def record_handoff(self, pages: int, nbytes: int) -> None:
         if pages <= 0 and nbytes <= 0:
@@ -208,16 +253,9 @@ class DisaggregatedExecutor(Executor):
         out = super().stats()
         out["prefill_devices"] = len(self.prefill_devices)
         out["decode_devices"] = len(self.decode_devices)
+        out["moved_bytes"] = self.moved_bytes
+        out["max_tick_moved_bytes"] = self.max_tick_moved_bytes
         return out
-
-
-def _pin(fn: Callable, dev) -> Callable:
-    """Run ``fn`` with ``dev`` as the default device, so uncommitted
-    inputs and fresh outputs land on the phase's worker group."""
-    def run(*args, **kwargs):
-        with jax.default_device(dev):
-            return fn(*args, **kwargs)
-    return run
 
 
 def make_executor(name: str, impls: Dict[str, Callable], *, mesh=None,

@@ -339,6 +339,9 @@ class KVPool:
                 shape = (L, n_pages, P, cfg.n_kv_heads, cfg.d_head)
                 self.caches.append({"k": jnp.zeros(shape, dtype),
                                     "v": jnp.zeros(shape, dtype)})
+        # the page dtype as allocated: kv_dtype "f32" means the model's
+        # float dtype (bf16 at published widths), not float32
+        self.page_dtype = next(iter(self.caches[0].values())).dtype
         # byte accounting is precomputed: the engine DONATES the cache
         # arrays to its jitted programs, so the initial leaves must never
         # be touched again after handing ``caches`` over

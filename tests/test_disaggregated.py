@@ -9,6 +9,9 @@ placement and spill are performance knobs, never semantics.
 """
 
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -451,3 +454,68 @@ if HAVE_HYPOTHESIS:
         eng.host_tier.check_invariants()
         assert eng.host_tier.used_pages() == 0, \
             "host-tier pages leaked across the interleaving"
+
+
+# ---------------------------------------------------------------------------
+# placement across real device groups (four virtual CPU devices)
+# ---------------------------------------------------------------------------
+
+_FOUR_DEVICES = r"""
+import dataclasses
+import jax, numpy as np
+from repro.configs.base import get_config
+from repro.models.transformer import init_params
+from repro.serving import SamplingParams, ServeConfig, ServingEngine
+from repro.serving.scheduler import PhaseAwareConfig
+
+assert len(jax.devices()) == 4, jax.devices()
+cfg = dataclasses.replace(get_config("qwen3-1.7b").reduced(), dtype="float32")
+params = init_params(jax.random.PRNGKey(0), cfg)
+rng = np.random.default_rng(0)
+ps = [rng.integers(0, cfg.vocab_size, (n,), dtype=np.int32) for n in (19, 7, 12)]
+
+def run(executor):
+    eng = ServingEngine(cfg, params, ServeConfig(
+        max_batch=2, max_len=64, phase=PhaseAwareConfig(
+            max_decode_batch=2, prefill_chunk=8, max_prefill_tokens=16),
+        paged=True, page_size=4, n_pages=32, executor=executor))
+    reqs = eng.generate(ps, SamplingParams(max_new_tokens=5))
+    return eng, [list(r.generated) for r in reqs]
+
+_, want = run("colocated")
+eng, got = run("disaggregated")
+ex = eng.executor
+assert got == want, (got, want)
+pre, dec = ex.prefill_devices[0], ex.decode_devices[0]
+assert (pre.id, dec.id) == (0, 2), (pre, dec)
+# the weights were copied to the decode device once and reused
+held = ex._params_on[dec]
+assert held[0] is params
+assert all(x.devices() == {dec} for x in jax.tree.leaves(held[1]))
+params_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
+pool_bytes = eng.pool.total_bytes()
+# the pool moves at phase switches, counted; the weights only once
+assert ex.moved_bytes >= params_bytes + pool_bytes, ex.moved_bytes
+assert ex.max_tick_moved_bytes >= pool_bytes
+before = ex.moved_bytes
+eng.generate(ps[:1], SamplingParams(max_new_tokens=3))
+assert ex._params_on[dec] is held
+assert ex.moved_bytes - before < params_bytes + 4 * pool_bytes
+assert {d.id for x in jax.tree.leaves(eng.cache) for d in x.devices()} <= {0, 2}
+print("OK")
+"""
+
+
+def test_disaggregated_placement_on_four_devices():
+    """Four devices, groups (0, 1) and (2, 3): prefill runs on device 0,
+    decode on device 2, the streams equal the colocated ones, the weights
+    are copied to the decode device once, and every pool move is counted
+    in ``moved_bytes``."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    p = subprocess.run([sys.executable, "-c", _FOUR_DEVICES], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0 and "OK" in p.stdout, \
+        f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"

@@ -571,3 +571,16 @@ def test_paged_rejects_recurrent_plans():
     cfg = tiny_cfg("mamba2-2.7b")
     with pytest.raises(ValueError):
         make_engine(cfg, paged=True)
+
+
+@pytest.mark.parametrize("dtype,kv,want", [
+    ("bfloat16", "f32", "bfloat16"),     # "f32" means the model's dtype
+    ("float32", "f32", "float32"),
+    ("bfloat16", "int8", "int8"),
+    ("bfloat16", "int4", "uint8"),
+])
+def test_pool_page_dtype_is_as_allocated(dtype, kv, want):
+    cfg = dataclasses.replace(get_config("qwen3-1.7b").reduced(), dtype=dtype)
+    pool = KVPool(cfg, n_slots=2, n_pages=8, page_size=4, kv_dtype=kv)
+    assert str(pool.page_dtype) == want
+    assert pool.caches[0]["k"].dtype == pool.page_dtype

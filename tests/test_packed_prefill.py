@@ -20,6 +20,7 @@ from repro.models.transformer import (
 )
 from repro.serving.engine import ServeConfig, ServingEngine
 from repro.serving.kv_pool import KVPool
+from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import (
     PackedPrefill,
     PhaseAwareConfig,
@@ -323,3 +324,56 @@ def test_packed_kernel_matches_reference():
     valid = np.asarray(seg.valid)
     np.testing.assert_allclose(np.asarray(out)[valid],
                                np.asarray(ref)[valid], atol=2e-5)
+
+
+# -- kernel routes and the kernel's segment alignment ------------------------------
+
+def test_attention_route_counters_name_the_path_taken():
+    """Trace-time counters: on the CPU packed prefill takes its jnp path
+    and paged decode the (interpreted) kernel; a softcapped plan gives
+    decode way to jnp, and the counter shows it."""
+    from repro.models.attention import (attention_route_counts,
+                                        reset_attention_route_counts)
+    cfg = tiny_cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    sp = SamplingParams(max_new_tokens=3)
+    reset_attention_route_counts()
+    _engine(cfg, params, True, paged=True).generate([[1, 2, 3, 4, 5]], sp)
+    routes = attention_route_counts()
+    assert routes.get("packed_prefill.jnp", 0) > 0
+    assert routes.get("paged_decode.kernel", 0) > 0
+    assert "packed_prefill.kernel" not in routes
+    capped = dataclasses.replace(
+        cfg, attn=dataclasses.replace(cfg.attn, logit_softcap=30.0))
+    reset_attention_route_counts()
+    _engine(capped, params, True, paged=True).generate([[1, 2, 3]], sp)
+    assert attention_route_counts().get("paged_decode.jnp", 0) > 0
+    reset_attention_route_counts()
+    assert attention_route_counts() == {}
+
+
+@pytest.mark.parametrize("backend,softcap,kv,paged,want", [
+    ("tpu", 0.0, "f32", True, 128),    # the kernel runs: its query tile
+    ("tpu", 0.0, "int8", True, 8),     # quantized pool: jnp path
+    ("tpu", 30.0, "f32", True, 8),     # softcap: jnp path
+    ("cpu", 0.0, "f32", True, 8),      # no TPU: jnp path
+    ("tpu", 0.0, "f32", False, 8),     # dense arena: jnp path
+])
+def test_pack_alignment_follows_the_kernel(monkeypatch, backend, softcap,
+                                           kv, paged, want):
+    """Where the Pallas packed-prefill kernel runs, the engine aligns its
+    packed segments to the kernel's 128-token tile whatever
+    PhaseAwareConfig.pack_align (default 8) says, so the default paged
+    path reaches the kernel; elsewhere the user's alignment stands."""
+    from repro.models import attention
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: backend)
+    cfg = tiny_cfg()
+    cfg = dataclasses.replace(
+        cfg, attn=dataclasses.replace(cfg.attn, logit_softcap=softcap))
+    assert attention.packed_kernel_align(softcap, kv != "f32") == (
+        128 if (backend, softcap, kv) == ("tpu", 0.0, "f32") else 0)
+    sc = ServeConfig(max_batch=2, max_len=64, paged=paged, page_size=8,
+                     n_pages=16, kv_dtype=kv,
+                     phase=PhaseAwareConfig(prefill_chunk=8))
+    eng = ServingEngine(cfg, init_params(jax.random.PRNGKey(0), cfg), sc)
+    assert eng._pack_align == want
