@@ -179,6 +179,18 @@ __all__ = [
 
 
 @dataclass
+class _Call:
+    """One phase-program call, prepared: every device argument built, so
+    launching it is the jitted call alone.  The program takes
+    ``(params, *head, cache, *tail, all_greedy)``."""
+    kind: str
+    shape: Tuple[int, ...]            # the compile key's shape
+    head: Tuple[Any, ...]             # device arguments before the cache
+    tail: Tuple[Any, ...]             # ... and after it
+    all_greedy: bool
+
+
+@dataclass
 class _SwapHandle:
     """Where a swapped-out request's KV lives while it waits in the queue
     (``Request.swap``): per-run host-tier page lists in block-table row
@@ -878,9 +890,14 @@ class ServingEngine:
             self.slot_req[slot] = req
             self._try_prefix_attach(req)
             admitted.append(req)
-        if self.tracer.enabled and admitted:
-            t = self.tracer.now()
+        if not admitted:
+            return admitted
+        t = time.monotonic()
+        for req in admitted:
+            req.t_admit = req.t_admit or t      # the first admission only
+        if self.tracer.enabled:
             for req in admitted:
+                # t is t_admit on a first admission
                 self.tracer.request_span(
                     req.req_id, "queued", req.t_requeue or req.t_submit, t,
                     cached_tokens=req.cached_tokens,
@@ -1315,6 +1332,18 @@ class ServingEngine:
                 return False
 
     # -- phase execution --------------------------------------------------------
+    # Each phase program runs in three profiler spans: ``prepare`` (page
+    # claims, host arrays, their device copies), ``launch`` (the jitted
+    # call, which returns before the device finishes) and ``sync`` (the
+    # ``_to_host`` wait for its tokens).
+    def _launch(self, group: str, call: _Call) -> Any:
+        """Note ``call``'s compile key and launch its program; returns the
+        sampled tokens, still on the device."""
+        self._note_compile(group, call.kind, call.shape, call.all_greedy)
+        out, self.cache = self._program(group, call.kind)(
+            self.params, *call.head, self.cache, *call.tail, call.all_greedy)
+        return out
+
     def _run_prefill_tick(self, plan: TickPlan) -> None:
         """Execute the plan's prefill chunks on the planned worker group."""
         reqs = self._by_id()
@@ -1323,57 +1352,22 @@ class ServingEngine:
         if not chunks:
             return
         if not self.chunked:
-            # atomic whole-prompt prefill (SSM / shared-attn state handoff)
-            self._prefill_progress = True
-            for req, take in chunks:
-                tokens = jnp.asarray(req.prompt[None], jnp.int32)
-                pp, all_greedy = self._pack_params([(0, req)], 1)
-                tw0 = self.tracer.now()
-                self._note_compile(plan.prefill_group, "whole",
-                                   (req.prompt_len,), all_greedy)
-                toks, self.cache = self._program(plan.prefill_group, "whole")(
-                    self.params, tokens, jnp.int32(req.slot), self.cache,
-                    *pp, all_greedy)
-                if self.tracer.enabled:
-                    self.tracer.request_span(
-                        req.req_id, "prefill_chunk", tw0, self.tracer.now(),
-                        take=req.prompt_len, offset=0)
-                req.prefill_pos = req.prompt_len
-                self.prefill_tokens_executed += req.prompt_len
-                self.prefill_launches += 1
-                self.prefill_rows_executed += req.prompt_len
-                self._start_decoding(req, self._to_host(toks)[0])
+            self._run_whole_prefill(plan, chunks)
             return
-
-        if self.paged:
-            # claim the chunks' pages; the scheduler planned against the
-            # pool headroom, so this succeeds — trim defensively (one
-            # query, one grow) if a same-tick race says otherwise.  Any
-            # SHARED page the chunk would dirty (a ring wrap over attached
-            # prefix pages) is copied first; if no copy target exists even
-            # after reclaiming cached pages, the chunk rolls back and
-            # waits for a later tick.
-            claimed = []
-            for req, take in chunks:
-                take = min(take, self.pool.max_grow_tokens(req.slot))
-                if take <= 0 or not self.pool.grow(req.slot,
-                                                   req.prefill_pos + take):
-                    continue
-                if not self._ensure_writable(req.slot, req.prefill_pos,
-                                             req.prefill_pos + take):
-                    self.pool.shrink(req.slot, req.prefill_pos)
-                    continue
-                claimed.append((req, take))
-            chunks = claimed
-            if not chunks:
-                return
+        with self.tracer.span("serve.prefill.prepare"):
+            if self.paged:
+                chunks = self._claim_prefill_pages(chunks)
+            call = None
+            if chunks:
+                call = (self._packed_prefill_call if self._packed
+                        else self._padded_prefill_call)(chunks)
+        if call is None:
+            return
         self._prefill_progress = True
 
         tp0 = self.tracer.now()
-        if self._packed:
-            toks = self._launch_packed_prefill(plan, chunks)
-        else:
-            toks = self._launch_padded_prefill(plan, chunks)
+        with self.tracer.span("serve.prefill.launch"):
+            toks = self._launch(plan.prefill_group, call)
         if self.tracer.enabled:
             tp1 = self.tracer.now()     # one launch serves every chunk
             for req, take in chunks:
@@ -1387,10 +1381,57 @@ class ServingEngine:
             req.prefill_pos += take
             if req.prefill_pos >= self._effective_len(req):
                 if sampled is None:
-                    sampled = self._to_host(toks)   # one transfer per tick
+                    with self.tracer.span("serve.prefill.sync"):
+                        sampled = self._to_host(toks)  # one transfer a tick
                 self._start_decoding(req, sampled[i])
 
-    def _launch_padded_prefill(self, plan: TickPlan, chunks) -> Any:
+    def _run_whole_prefill(self, plan: TickPlan, chunks) -> None:
+        """Atomic whole-prompt prefill (SSM / shared-attn state handoff):
+        one call, and one transfer, per request."""
+        self._prefill_progress = True
+        for req, take in chunks:
+            with self.tracer.span("serve.prefill.prepare"):
+                pp, all_greedy = self._pack_params([(0, req)], 1)
+                call = _Call("whole", (req.prompt_len,),
+                             (jnp.asarray(req.prompt[None], jnp.int32),
+                              jnp.int32(req.slot)), pp, all_greedy)
+            tw0 = self.tracer.now()
+            with self.tracer.span("serve.prefill.launch"):
+                toks = self._launch(plan.prefill_group, call)
+            if self.tracer.enabled:
+                self.tracer.request_span(
+                    req.req_id, "prefill_chunk", tw0, self.tracer.now(),
+                    take=req.prompt_len, offset=0)
+            req.prefill_pos = req.prompt_len
+            self.prefill_tokens_executed += req.prompt_len
+            self.prefill_launches += 1
+            self.prefill_rows_executed += req.prompt_len
+            with self.tracer.span("serve.prefill.sync"):
+                tok = self._to_host(toks)[0]
+            self._start_decoding(req, tok)
+
+    def _claim_prefill_pages(self, chunks):
+        """Claim the chunks' pages; the scheduler planned against the pool
+        headroom, so this succeeds — trim defensively (one query, one
+        grow) if a same-tick race says otherwise.  Any SHARED page the
+        chunk would dirty (a ring wrap over attached prefix pages) is
+        copied first; if no copy target exists even after reclaiming
+        cached pages, the chunk rolls back and waits for a later tick.
+        Returns the chunks that hold their pages."""
+        claimed = []
+        for req, take in chunks:
+            take = min(take, self.pool.max_grow_tokens(req.slot))
+            if take <= 0 or not self.pool.grow(req.slot,
+                                               req.prefill_pos + take):
+                continue
+            if not self._ensure_writable(req.slot, req.prefill_pos,
+                                         req.prefill_pos + take):
+                self.pool.shrink(req.slot, req.prefill_pos)
+                continue
+            claimed.append((req, take))
+        return claimed
+
+    def _padded_prefill_call(self, chunks) -> _Call:
         """The tick's chunks as one padded [N, C] batch (pow2 buckets bound
         the number of compiled shapes).  Row i samples chunk i."""
         N = _bucket(len(chunks), self.sc.max_batch)
@@ -1411,24 +1452,14 @@ class ServingEngine:
         pp, all_greedy = self._pack_params(
             [(i, req) for i, (req, _) in enumerate(chunks)], N)
         self.prefill_rows_executed += N * C
+        head = (jnp.asarray(tokens), jnp.asarray(offs), jnp.asarray(lens),
+                jnp.asarray(slots))
         if self.paged:
-            self._note_compile(plan.prefill_group, "chunk_paged", (N, C),
-                               all_greedy)
-            toks, self.cache = self._program(plan.prefill_group,
-                                             "chunk_paged")(
-                self.params, jnp.asarray(tokens), jnp.asarray(offs),
-                jnp.asarray(lens), jnp.asarray(slots), self.cache,
-                self.pool.block_tables(), *pp, all_greedy)
-        else:
-            self._note_compile(plan.prefill_group, "chunk", (N, C),
-                               all_greedy)
-            toks, self.cache = self._program(plan.prefill_group, "chunk")(
-                self.params, jnp.asarray(tokens), jnp.asarray(offs),
-                jnp.asarray(lens), jnp.asarray(slots), self.cache,
-                *pp, all_greedy)
-        return toks
+            return _Call("chunk_paged", (N, C), head,
+                         (self.pool.block_tables(), *pp), all_greedy)
+        return _Call("chunk", (N, C), head, pp, all_greedy)
 
-    def _launch_packed_prefill(self, plan: TickPlan, chunks) -> Any:
+    def _packed_prefill_call(self, chunks) -> _Call:
         """The tick's chunks as ONE flat [T] token stream: chunk i occupies
         ``[starts[i], starts[i] + take)``, T is the pow2-bucketed packed
         length, and pad gaps carry no request (start sentinel T, slot
@@ -1459,22 +1490,12 @@ class ServingEngine:
         pp, all_greedy = self._pack_params(
             [(i, req) for i, (req, _) in enumerate(chunks)], Nb)
         self.prefill_rows_executed += T
+        head = (jnp.asarray(tokens), jnp.asarray(starts), jnp.asarray(offs),
+                jnp.asarray(lens), jnp.asarray(slots))
         if self.paged:
-            self._note_compile(plan.prefill_group, "packed_paged", (T, Nb),
-                               all_greedy)
-            toks, self.cache = self._program(plan.prefill_group,
-                                             "packed_paged")(
-                self.params, jnp.asarray(tokens), jnp.asarray(starts),
-                jnp.asarray(offs), jnp.asarray(lens), jnp.asarray(slots),
-                self.cache, self.pool.block_tables(), *pp, all_greedy)
-        else:
-            self._note_compile(plan.prefill_group, "packed", (T, Nb),
-                               all_greedy)
-            toks, self.cache = self._program(plan.prefill_group, "packed")(
-                self.params, jnp.asarray(tokens), jnp.asarray(starts),
-                jnp.asarray(offs), jnp.asarray(lens), jnp.asarray(slots),
-                self.cache, *pp, all_greedy)
-        return toks
+            return _Call("packed_paged", (T, Nb), head,
+                         (self.pool.block_tables(), *pp), all_greedy)
+        return _Call("packed", (T, Nb), head, pp, all_greedy)
 
     # -- speculative draft/verify ------------------------------------------------
     def _spec_budget(self, r: Request) -> int:
@@ -1519,12 +1540,13 @@ class ServingEngine:
             slots[i] = r.slot
         pp, all_greedy = self._pack_params(
             [(i, r) for i, (r, _) in enumerate(rows)], N)
+        call = _Call("verify", (N, C),
+                     (jnp.asarray(tokens), jnp.asarray(offs),
+                      jnp.asarray(lens), jnp.asarray(slots)),
+                     (self.pool.block_tables(), jnp.asarray(draft), *pp),
+                     all_greedy)
         tv0 = self.tracer.now()
-        self._note_compile(plan.verify_group, "verify", (N, C), all_greedy)
-        out, self.cache = self._program(plan.verify_group, "verify")(
-            self.params, jnp.asarray(tokens), jnp.asarray(offs),
-            jnp.asarray(lens), jnp.asarray(slots), self.cache,
-            self.pool.block_tables(), jnp.asarray(draft), *pp, all_greedy)
+        out = self._launch(plan.verify_group, call)
         packed = self._to_host(out)                 # [N, C+1], one transfer
         tv1 = self.tracer.now()
         for i, (r, d) in enumerate(rows):
@@ -1601,23 +1623,53 @@ class ServingEngine:
         if self.spec is not None and active:
             rows, active = self._plan_speculation(active)
             if rows:
-                self._run_verify_tick(plan, rows)
-        if self.paged and active:
-            # each decode write may cross into a fresh page (or, shared-
-            # prefix, into a page another request still reads — COW).
-            # Grow oldest-first; when the pool is out, reclaim CACHED
-            # pages LRU-first, and only if the cache cannot help PREEMPT
-            # the youngest page holder — its pages come back, it
-            # re-queues for recompute
-            survivors = []
-            for r in sorted(active, key=lambda r: r.req_id):
-                if r.state != RequestState.DECODING or r.slot < 0:
-                    continue                        # evicted earlier this loop
-                if self._grow_for_decode(r):
-                    survivors.append(r)
-            active = survivors
+                with self.tracer.span("serve.verify"):
+                    self._run_verify_tick(plan, rows)
         if not active:
             return
+        with self.tracer.span("serve.decode.prepare"):
+            if self.paged:
+                # each decode write may cross into a fresh page (or,
+                # shared-prefix, into a page another request still reads
+                # — COW).  Grow oldest-first; when the pool is out,
+                # reclaim CACHED pages LRU-first, and only if the cache
+                # cannot help PREEMPT the youngest page holder — its pages
+                # come back, it re-queues for recompute
+                survivors = []
+                for r in sorted(active, key=lambda r: r.req_id):
+                    if r.state != RequestState.DECODING or r.slot < 0:
+                        continue                # evicted earlier this loop
+                    if self._grow_for_decode(r):
+                        survivors.append(r)
+                active = survivors
+            if active:
+                call, emitted = self._decode_call(active)
+        if not active:
+            return
+        td0 = self.tracer.now()
+        with self.tracer.span("serve.decode.launch"):
+            toks = self._launch(plan.decode_group, call)
+        with self.tracer.span("serve.decode.sync"):
+            sampled = self._to_host(toks)           # one transfer per tick
+        td1 = self.tracer.now()
+        for row, r in emitted:
+            self._append_token(r, sampled[row])
+            # occupancy is counted at emission, not at planning: a request
+            # preempted by its own growth failure emitted nothing and must
+            # not drag tokens_per_tick below the non-speculative 1.0 floor
+            self.decode_tokens_emitted += 1
+            self.decode_slot_ticks += 1
+            self.slot_pos[r.slot] += 1
+            if self.tracer.enabled:
+                self.tracer.request_span(r.req_id, "decode", td0, td1,
+                                         tokens=1)
+            if self._finished(r):
+                self._retire(r)
+
+    def _decode_call(self, active: List[Request]
+                     ) -> Tuple[_Call, List[Tuple[int, Request]]]:
+        """The decode step's call, and which row of its tokens each
+        request reads."""
         B = self.sc.max_batch
         if self.paged:
             # the page pool addresses KV through the CALL's block tables,
@@ -1636,56 +1688,30 @@ class ServingEngine:
                 pos[i] = self.slot_pos[r.slot]
             pp, all_greedy = self._pack_params(
                 [(i, r) for i, r in enumerate(active)], nb)
-            td0 = self.tracer.now()
-            self._note_compile(plan.decode_group, "decode_paged", (nb,),
-                               all_greedy)
             # pad rows carry all-sentinel block-table rows: their scatters
             # drop — the paged analogue of the dense slot_mask
-            toks, self.cache = self._program(plan.decode_group,
-                                             "decode_paged")(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(pos),
-                self.pool.block_tables(rows=[r.slot for r in active], n=nb),
-                *pp, all_greedy)
-            sampled = self._to_host(toks)           # one transfer per tick
-            emitted = [(i, r) for i, r in enumerate(active)]
+            tables = self.pool.block_tables(rows=[r.slot for r in active],
+                                            n=nb)
+            return (_Call("decode_paged", (nb,), (jnp.asarray(tokens),),
+                          (jnp.asarray(pos), tables, *pp), all_greedy),
+                    list(enumerate(active)))
+        # the dense arena is slot-indexed, so the batch stays [B]
+        if self.cfg.n_codebooks > 1:
+            tokens = np.zeros((B, self.cfg.n_codebooks, 1), np.int32)
         else:
-            # the dense arena is slot-indexed, so the batch stays [B]
-            if self.cfg.n_codebooks > 1:
-                tokens = np.zeros((B, self.cfg.n_codebooks, 1), np.int32)
-            else:
-                tokens = np.zeros((B, 1), np.int32)
-            mask = np.zeros((B,), bool)
-            for r in active:
-                tokens[r.slot, ..., 0] = r.generated[-1]
-                mask[r.slot] = True
-            # ragged decode: per-slot positions (vector pos -> per-slot
-            # rope, per-slot cache write index, per-slot validity mask)
-            pos = np.where(self.slot_pos >= 0,
-                           self.slot_pos, 0).astype(np.int32)
-            pp, all_greedy = self._pack_params(
-                [(r.slot, r) for r in active], B)
-            td0 = self.tracer.now()
-            self._note_compile(plan.decode_group, "decode", (B,), all_greedy)
-            toks, self.cache = self._program(plan.decode_group, "decode")(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(pos), jnp.asarray(mask), *pp, all_greedy)
-            sampled = self._to_host(toks)           # one transfer per tick
-            emitted = [(r.slot, r) for r in active]
-        td1 = self.tracer.now()
-        for row, r in emitted:
-            self._append_token(r, sampled[row])
-            # occupancy is counted at emission, not at planning: a request
-            # preempted by its own growth failure emitted nothing and must
-            # not drag tokens_per_tick below the non-speculative 1.0 floor
-            self.decode_tokens_emitted += 1
-            self.decode_slot_ticks += 1
-            self.slot_pos[r.slot] += 1
-            if self.tracer.enabled:
-                self.tracer.request_span(r.req_id, "decode", td0, td1,
-                                         tokens=1)
-            if self._finished(r):
-                self._retire(r)
+            tokens = np.zeros((B, 1), np.int32)
+        mask = np.zeros((B,), bool)
+        for r in active:
+            tokens[r.slot, ..., 0] = r.generated[-1]
+            mask[r.slot] = True
+        # ragged decode: per-slot positions (vector pos -> per-slot
+        # rope, per-slot cache write index, per-slot validity mask)
+        pos = np.where(self.slot_pos >= 0,
+                       self.slot_pos, 0).astype(np.int32)
+        pp, all_greedy = self._pack_params([(r.slot, r) for r in active], B)
+        return (_Call("decode", (B,), (jnp.asarray(tokens),),
+                      (jnp.asarray(pos), jnp.asarray(mask), *pp), all_greedy),
+                [(r.slot, r) for r in active])
 
     # -- tick loop ---------------------------------------------------------------
     def step(self) -> List[RequestOutput]:
@@ -1698,20 +1724,45 @@ class ServingEngine:
         tokens; recompute-on-resume replays no draws), but one that
         GAINED tokens earlier in the same tick still reports them; an
         ``abort()`` between ticks returns its terminal output directly
-        from ``abort``."""
-        t0 = time.monotonic()
-        self.executor.begin_tick()
-        self._prefill_progress = False
-        # deferred submits re-enter as soon as the backlog has room —
-        # BEFORE this tick's admission so they compete for freed slots
-        self._reconsider_deferred()
-        # snapshot for incremental outputs: every request that can gain
-        # tokens this tick is in the queue or a slot right now
-        counts0 = {r.req_id: len(r.generated) for r in self.queue}
-        counts0.update({r.req_id: len(r.generated)
-                        for r in self.slot_req if r is not None})
-        done0 = len(self.done)
-        self._admit()
+        from ``abort``.
+
+        The tick runs in the ``serve.step`` span; its phases in
+        ``serve.admit``, ``serve.plan``, the prepare/launch/sync spans of
+        each phase program, and ``serve.emit`` (``Tracer.span``)."""
+        with self.tracer.span("serve.step") as span:
+            t0 = time.monotonic()
+            self.executor.begin_tick()
+            self._prefill_progress = False
+            with self.tracer.span("serve.admit"):
+                # deferred submits re-enter as soon as the backlog has
+                # room — BEFORE this tick's admission so they compete for
+                # freed slots
+                self._reconsider_deferred()
+                # snapshot for incremental outputs: every request that can
+                # gain tokens this tick is in the queue or a slot right now
+                counts0 = {r.req_id: len(r.generated) for r in self.queue}
+                counts0.update({r.req_id: len(r.generated)
+                                for r in self.slot_req if r is not None})
+                done0 = len(self.done)
+                self._admit()
+            with self.tracer.span("serve.plan"):
+                plan = self._plan_tick()
+            if plan.prefill_chunks:
+                self._run_prefill_tick(plan)
+            if plan.decode_reqs:
+                self._run_decode_tick(plan)
+            with self.tracer.span("serve.emit"):
+                if (self.paged and not plan.decode_reqs
+                        and not self._prefill_progress):
+                    self._break_prefill_stall()
+                rec = self._record_tick(plan, t0)
+                outputs = self._tick_outputs(counts0, done0)
+        if self.tracer.enabled:
+            # the tick event spans serve.step exactly, so the two nest
+            self._trace_tick(span.t0, span.t1, rec)
+        return outputs
+
+    def _plan_tick(self) -> TickPlan:
         # entries carry (priority, TTFT deadline) so plan_tick can order
         # the prefill budget SLO-aware: class first, then EDF, then age
         # (FIFO) — for deadline-free requests this is the original pure
@@ -1727,28 +1778,25 @@ class ServingEngine:
         decoding = [r.req_id for r in self.slot_req
                     if r is not None and r.state == RequestState.DECODING]
         spec_k = self.spec.k if self.spec is not None else 0
-        if self.paged:
-            # token-level admission: prefill work is planned against the
-            # pool's free pages, with this tick's decode growth reserved
-            # (a speculative verify window grows by up to k+1 tokens — the
-            # windows are charged like mini prefill chunks)
-            headroom = self.pool.headroom_pages(
-                [self.pool.len_of(r.slot) for r in self.slot_req
-                 if r is not None and r.state == RequestState.DECODING],
-                growth=spec_k + 1)
-            plan = self.scheduler.plan_tick(
-                prefilling, decoding, free_pages=headroom,
-                page_size=self.sc.page_size,
-                capacity=self.pool.widest_capacity(), spec_k=spec_k)
-        else:
-            plan = self.scheduler.plan_tick(prefilling, decoding,
+        if not self.paged:
+            return self.scheduler.plan_tick(prefilling, decoding,
                                             spec_k=spec_k)
-        if plan.prefill_chunks:
-            self._run_prefill_tick(plan)
-        if plan.decode_reqs:
-            self._run_decode_tick(plan)
-        if self.paged and not plan.decode_reqs and not self._prefill_progress:
-            self._break_prefill_stall()
+        # token-level admission: prefill work is planned against the
+        # pool's free pages, with this tick's decode growth reserved (a
+        # speculative verify window grows by up to k+1 tokens — the
+        # windows are charged like mini prefill chunks)
+        headroom = self.pool.headroom_pages(
+            [self.pool.len_of(r.slot) for r in self.slot_req
+             if r is not None and r.state == RequestState.DECODING],
+            growth=spec_k + 1)
+        return self.scheduler.plan_tick(
+            prefilling, decoding, free_pages=headroom,
+            page_size=self.sc.page_size,
+            capacity=self.pool.widest_capacity(), spec_k=spec_k)
+
+    def _record_tick(self, plan: TickPlan, t0: float) -> TickRecord:
+        """Append the tick's ``TickRecord`` and fold it into the counters
+        and the tick-cost EMA."""
         resident = self.pool.resident_bytes() if self.paged else 0
         self.kv_resident_peak = max(self.kv_resident_peak, resident)
         # per-tick counters are registry DELTAS against the previous
@@ -1790,32 +1838,15 @@ class ServingEngine:
             self._tick_wall_ema = (rec.wall_s if self._tick_wall_n == 1 else
                                    0.8 * self._tick_wall_ema
                                    + 0.2 * rec.wall_s)
-        if self.tracer.enabled:
-            # the TickRecord twin: every rec counter appears as a tick-span
-            # arg, so summing an arg across the tick track reproduces the
-            # registry total (the conservation law the tests pin)
-            self.tracer.tick_span(
-                t0, t0 + rec.wall_s, index=rec.index,
-                prefill_reqs=list(rec.prefill_reqs),
-                prefill_tokens=rec.prefill_tokens,
-                decode_reqs=list(rec.decode_reqs),
-                prefill_group=rec.prefill_group or "",
-                decode_group=rec.decode_group or "",
-                preemptions=rec.preemptions,
-                kv_resident_bytes=rec.kv_resident_bytes,
-                spec_drafted=rec.spec_drafted,
-                spec_accepted=rec.spec_accepted,
-                new_compiles=rec.new_compiles,
-                migrated_pages=rec.migrated_pages,
-                migrated_bytes=rec.migrated_bytes,
-                swap_out_bytes=rec.swap_out_bytes,
-                swap_in_bytes=rec.swap_in_bytes,
-                host_resident_pages=rec.host_resident_pages)
         self.tick_log.append(rec)
         self._n_ticks += 1
         self._n_prefill_ticks += bool(rec.prefill_reqs)
         self._n_decode_ticks += bool(rec.decode_reqs)
         self._n_mixed_ticks += rec.mixed
+        return rec
+
+    def _tick_outputs(self, counts0: Dict[int, int],
+                      done0: int) -> List[RequestOutput]:
         # incremental outputs: live slot holders + requests retired this
         # tick + requests preempted BACK TO THE QUEUE after gaining tokens
         # earlier in the same tick (a growth victim whose prefill had just
@@ -1837,6 +1868,28 @@ class ServingEngine:
                     finished=finished,
                     finish_reason=r.finish_reason if finished else None))
         return outputs
+
+    def _trace_tick(self, t0: float, t1: float, rec: TickRecord) -> None:
+        # the TickRecord twin: every rec counter appears as a tick-span
+        # arg, so summing an arg across the tick track reproduces the
+        # registry total (the conservation law the tests pin)
+        self.tracer.tick_span(
+            t0, t1, index=rec.index,
+            prefill_reqs=list(rec.prefill_reqs),
+            prefill_tokens=rec.prefill_tokens,
+            decode_reqs=list(rec.decode_reqs),
+            prefill_group=rec.prefill_group or "",
+            decode_group=rec.decode_group or "",
+            preemptions=rec.preemptions,
+            kv_resident_bytes=rec.kv_resident_bytes,
+            spec_drafted=rec.spec_drafted,
+            spec_accepted=rec.spec_accepted,
+            new_compiles=rec.new_compiles,
+            migrated_pages=rec.migrated_pages,
+            migrated_bytes=rec.migrated_bytes,
+            swap_out_bytes=rec.swap_out_bytes,
+            swap_in_bytes=rec.swap_in_bytes,
+            host_resident_pages=rec.host_resident_pages)
 
     def counts(self) -> Dict[str, int]:
         """Queue/slot/done occupancy (the old ``step()`` return value),

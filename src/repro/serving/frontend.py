@@ -295,15 +295,21 @@ class AsyncEngine:
         ``asyncio.sleep(0)`` between ticks hands the loop to every ready
         client exactly once, so submissions posted while a tick ran are
         admitted before the next one — continuous batching across the
-        async boundary."""
+        async boundary.  The drain and the fan-out run in the engine
+        tracer's ``serve.frontend.drain`` / ``serve.frontend.fanout``
+        spans."""
+        span = self.engine.tracer.span
         try:
             while True:
-                self._drain_mailbox()
+                with span("serve.frontend.drain"):
+                    self._drain_mailbox()
                 if self._closing:
                     break
                 if self.engine._live():          # noqa: SLF001 (owner facade)
-                    for out in self.engine.step():
-                        self._dispatch(out)
+                    outs = self.engine.step()
+                    with span("serve.frontend.fanout"):
+                        for out in outs:
+                            self._dispatch(out)
                     await asyncio.sleep(0)
                 else:
                     self._wake.clear()

@@ -11,7 +11,9 @@ out so one screenful answers HALO's attribution questions:
   tokens, preemptions, migrated pages/bytes (the 2.5D-link analogue),
   swap in/out bytes, new compiles, resident KV.  Summing a tick-arg
   across the track reproduces the registry total EXACTLY — the
-  conservation law tests/test_observability.py pins.
+  conservation law tests/test_observability.py pins.  The same track
+  holds the ``span`` events (``serve.step`` and the phases nested in
+  it, ``serve.frontend.*``), each the twin of a profiler annotation.
 * **track req_id + 1 (one per request)** — that request's lifecycle as
   an async "b"/"e" envelope (submit -> finish/abort) containing "X"
   phase spans: ``queued``, ``prefill_chunk`` (args: take, offset),
@@ -25,6 +27,14 @@ engine's call sites also guard, so span-argument work is skipped too),
 token streams are bit-identical with tracing on or off — the tracer
 never touches device state, only host timestamps.
 
+``span(name)`` is the one exception: it ALWAYS enters a
+``jax.profiler.TraceAnnotation``, whatever ``enabled`` says.  With no
+profiler recording that is a flag check in the profiler's C++ layer;
+under ``jax.profiler.trace`` it puts the span in the ``.xplane.pb`` on
+the same clock as the device's "XLA Modules" / "XLA Ops" lines, so a
+device-idle gap can be laid against the host phase that left it.  The
+profiler being on is the switch; there is no other.
+
 Timestamps: the engine stamps events with ``time.monotonic()`` seconds
 (``Request.t_submit`` etc. use the same clock); the tracer rebases to
 its construction instant and converts to the format's microseconds.
@@ -36,6 +46,8 @@ from __future__ import annotations
 import json
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: trace "process" id: one serving engine == one process row in Perfetto
 PID = 1
@@ -82,6 +94,15 @@ class Tracer:
         return tid
 
     # -- emitters ---------------------------------------------------------------
+    def span(self, name: str):
+        """Context manager over one phase of the serving loop: a
+        ``jax.profiler.TraceAnnotation(name)`` always, and when enabled
+        also an "X" event on the tick track.  Names are plain strings (no
+        ``#k=v#`` metadata) so a trace reader matches them exactly.  An
+        enabled span yields itself, with its engine-clock bounds in
+        ``t0`` / ``t1`` once it has closed."""
+        return _Span(self, name) if self.enabled else TraceAnnotation(name)
+
     def begin_request(self, req_id: int, t: float, **args: Any) -> None:
         """Open a request's lifecycle envelope (async "b"; closed by
         ``end_request`` at finish/abort)."""
@@ -113,7 +134,9 @@ class Tracer:
 
     def tick_span(self, t0: float, t1: float, **args: Any) -> None:
         """One engine tick on the tick track; ``args`` carry the
-        ``TickRecord`` twin the conservation tests sum over."""
+        ``TickRecord`` twin the conservation tests sum over.  The engine
+        gives it the bounds of the tick's ``serve.step`` span, so the
+        phase spans nest inside it."""
         if not self.enabled:
             return
         ts = self._ts(t0)
@@ -134,6 +157,12 @@ class Tracer:
             "ph": "i", "cat": "instant", "s": "t", "pid": PID, "tid": tid,
             "ts": self._ts(t), "name": name, "args": args})
 
+    def _complete(self, name: str, t0: float, t1: float) -> None:
+        ts = self._ts(t0)
+        self._events.append({
+            "ph": "X", "cat": "span", "pid": PID, "tid": TICK_TID,
+            "ts": ts, "dur": max(self._ts(t1) - ts, 0.0), "name": name})
+
     # -- export ------------------------------------------------------------------
     def events(self) -> List[Dict[str, Any]]:
         """The recorded trace events (the live list — cheap; callers
@@ -148,6 +177,27 @@ class Tracer:
     def write(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_json(), f)
+
+
+class _Span:
+    """An enabled tracer's span: the profiler annotation plus its twin
+    "X" event, appended when the span closes."""
+
+    __slots__ = ("_tracer", "_name", "_note", "t0", "t1")
+
+    def __init__(self, tracer: Tracer, name: str):
+        self._tracer, self._name = tracer, name
+        self._note = TraceAnnotation(name)
+
+    def __enter__(self) -> "_Span":
+        self._note.__enter__()
+        self.t0 = self._tracer._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = self._tracer._clock()
+        self._note.__exit__(*exc)
+        self._tracer._complete(self._name, self.t0, self.t1)
 
 
 __all__ = ["PID", "TICK_TID", "Tracer"]

@@ -56,6 +56,9 @@ class Request:
     n_preempted: int = 0                # pool-exhaustion evictions survived
     cached_tokens: int = 0              # tokens served from the prefix cache
     t_submit: float = 0.0
+    # first admission to a slot (time.monotonic, like t_submit); a
+    # re-admission after preemption leaves it as it was
+    t_admit: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
     t_requeue: float = 0.0              # last preemption re-queue instant

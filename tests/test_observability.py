@@ -249,6 +249,41 @@ def test_tracer_event_schema():
     json.dumps(doc)                          # must be serializable
 
 
+def test_tracer_span():
+    tr = Tracer(clock=make_clock())           # t0 = 101
+    with tr.span("serve.plan") as span:       # enters at 102, exits at 103
+        pass
+    ev, = [e for e in tr.events() if e["ph"] == "X"]
+    assert ev == {"ph": "X", "cat": "span", "pid": PID, "tid": TICK_TID,
+                  "ts": pytest.approx(1e6), "dur": pytest.approx(1e6),
+                  "name": "serve.plan"}
+    assert (span.t0, span.t1) == (102.0, 103.0)
+    with pytest.raises(KeyError):             # closes on the way out
+        with tr.span("serve.emit"):
+            raise KeyError("x")
+    assert [e["name"] for e in tr.events() if e["ph"] == "X"] == \
+        ["serve.plan", "serve.emit"]
+    off = Tracer(enabled=False)
+    with off.span("serve.plan"):
+        pass
+    assert off.events() == []
+
+
+def test_disabled_tracer_span_reaches_the_profiler(tmp_path):
+    """A disabled tracer's span is still a profiler annotation: under
+    ``jax.profiler.trace`` it lands among the host events, by its plain
+    name."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        with Tracer(enabled=False).span("serve.plan"):
+            jax.numpy.ones(4).sum().block_until_ready()
+    path, = Path(tmp_path).rglob("*.xplane.pb")
+    names = [e.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events]
+    assert names.count("serve.plan") == 1
+
+
 def test_tracer_disabled_is_inert():
     tr = Tracer(enabled=False)
     assert tr.now() == 0.0
@@ -366,6 +401,107 @@ def test_tracing_identity_and_trace_registry_reconciliation():
         assert any(lo - eps <= s["ts"] and s["ts"] + s["dur"] <= hi + eps
                    for lo, hi in windows), \
             f"{s['name']} span outside every tick window"
+    # the profiler-span twins: each tick event spans its serve.step
+    # exactly, and every engine phase span lies inside one
+    phases = [e for e in evs if e.get("cat") == "span"]
+    steps = [(e["ts"], e["ts"] + e["dur"]) for e in phases
+             if e["name"] == "serve.step"]
+    assert steps == windows
+    assert {"serve.admit", "serve.plan", "serve.emit",
+            "serve.prefill.launch", "serve.decode.sync"} <= \
+        {e["name"] for e in phases}
+    for e in phases:
+        assert any(lo <= e["ts"] and e["ts"] + e["dur"] <= hi
+                   for lo, hi in steps), e["name"]
+    # t_admit is the first admission, on the engine's clock: the swapped
+    # victim's re-admission leaves it, and its first queued span ends
+    # there
+    reqs = sorted(eng.done, key=lambda r: r.req_id)
+    assert len(reqs) == len(ps)
+    for r in reqs:
+        assert 0 < r.t_submit <= r.t_admit <= r.t_first_token
+    victim, = [r for r in reqs if r.n_preempted]
+    queued = sorted((s for s in by_name["queued"]
+                     if s["tid"] == victim_tid), key=lambda s: s["ts"])
+    assert victim_tid == victim.req_id + 1
+    t_admit_us = (victim.t_admit - tracer._t0) * 1e6
+    assert queued[0]["ts"] + queued[0]["dur"] == pytest.approx(t_admit_us)
+    assert queued[1]["ts"] + queued[1]["dur"] > t_admit_us + eps
+
+
+def test_one_tick_nests_its_phase_spans_in_order():
+    """A mixed tick (one prompt prefills to completion beside a decoding
+    request) runs each phase in its own span, in order, inside
+    ``serve.step``; the tick event spans ``serve.step`` exactly."""
+    cfg = tiny_cfg()
+    tracer = Tracer()
+    eng = make_engine(cfg, tracer=tracer)
+    a, b = prompts(cfg, 2, 8, seed=3)
+    ra = eng.submit(a, sampling=SamplingParams(max_new_tokens=4))
+    while ra.state != RequestState.DECODING:
+        eng.step()
+    rb = eng.submit(b, sampling=SamplingParams(max_new_tokens=4))
+    n0 = len(tracer.events())
+    eng.step()
+    assert rb.state == RequestState.DECODING and len(ra.generated) == 2
+    evs = tracer.events()[n0:]
+    phases = [e for e in evs if e.get("cat") == "span"]
+    step, = [e for e in phases if e["name"] == "serve.step"]
+    inner = sorted((e for e in phases if e is not step),
+                   key=lambda e: e["ts"])
+    assert [e["name"] for e in inner] == [
+        "serve.admit", "serve.plan",
+        "serve.prefill.prepare", "serve.prefill.launch", "serve.prefill.sync",
+        "serve.decode.prepare", "serve.decode.launch", "serve.decode.sync",
+        "serve.emit"]
+    end = step["ts"] + step["dur"]
+    for e, nxt in zip(inner, inner[1:] + [None]):
+        assert step["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end
+        if nxt is not None:
+            assert e["ts"] + e["dur"] <= nxt["ts"]
+    tick, = [e for e in evs if e.get("cat") == "tick"]
+    assert (tick["ts"], tick["dur"]) == (step["ts"], step["dur"])
+
+
+def test_async_engine_spans_drain_and_fanout():
+    import asyncio
+
+    from repro.serving import AsyncEngine
+    cfg = tiny_cfg()
+    tracer = Tracer()
+    eng = make_engine(cfg, tracer=tracer)
+
+    async def go():
+        async with AsyncEngine(eng) as fe:
+            h = await fe.submit(prompts(cfg, 1, 8)[0], max_new_tokens=3)
+            return [out async for out in h]
+    outs = asyncio.run(go())
+    assert outs[-1].finished
+    phases = [e for e in tracer.events() if e.get("cat") == "span"]
+    steps = [(e["ts"], e["ts"] + e["dur"]) for e in phases
+             if e["name"] == "serve.step"]
+    fanouts = [e for e in phases if e["name"] == "serve.frontend.fanout"]
+    assert len(fanouts) == len(steps) == eng.n_ticks
+    assert any(e["name"] == "serve.frontend.drain" for e in phases)
+    # the owner loop's work lies outside every engine tick
+    for e in phases:
+        if e["name"].startswith("serve.frontend."):
+            assert not any(lo < e["ts"] < hi for lo, hi in steps)
+
+
+def test_t_admit_survives_a_preemption():
+    cfg = tiny_cfg()
+    eng = make_engine(cfg)
+    r = eng.submit(prompts(cfg, 1, 8)[0], max_new_tokens=6)
+    assert r.t_admit == 0.0
+    while len(r.generated) < 2:
+        eng.step()
+    t_admit = r.t_admit
+    assert r.t_submit <= t_admit <= r.t_first_token
+    eng._preempt(r)
+    eng.run_until_drained()
+    assert r.n_preempted == 1 and r.state == RequestState.DONE
+    assert r.t_admit == t_admit
 
 
 def test_counts_and_spec_stats_are_registry_views():
