@@ -623,7 +623,7 @@ def attn_chunk_packed(params, x, seg: PackedSegs, cache_k, cache_v, *,
     over its OWN segment's ring history plus the causally-visible tokens of
     the same segment inside the stream.  On TPU with a tile-aligned stream
     the attention sweep runs in the Pallas ``packed_prefill_attention``
-    kernel (the dense arena is presented as a 1-page-per-segment pool view).
+    kernel (the dense arena is presented as a pool of ring pages).
 
     Returns (out [1, T, d_model], new_cache_k, new_cache_v).
     """
@@ -636,11 +636,17 @@ def attn_chunk_packed(params, x, seg: PackedSegs, cache_k, cache_v, *,
 
     if _use_packed_kernel(pack_align, T, softcap, window):
         from repro.kernels import ops as _kops
-        # dense arena as a page view: one R-sized page per slot, block
-        # table = the segment's slot (sentinel B for pad segments drops
-        # to a clamped page whose entries prev_pos-mask out anyway)
+        # the dense arena as a page pool: each slot's ring cut into pages
+        # of Pd tokens (the largest power of two <= 128 dividing R), a
+        # segment's block-table row its slot's pages in ring order (pad
+        # segments' slot B maps past the pool: the sentinel)
+        Pd = math.gcd(R, 128)
+        nr = R // Pd
+        rows = (seg.slots[:, None] * nr
+                + jnp.arange(nr, dtype=jnp.int32)[None, :])
         ctx = _kops.packed_prefill_attention(
-            q, k, v, cache_k, cache_v, seg.slots[:, None],
+            q, k, v, cache_k.reshape(B * nr, Pd, *cache_k.shape[2:]),
+            cache_v.reshape(B * nr, Pd, *cache_v.shape[2:]), rows,
             seg.starts, seg.offsets, seg.lengths,
             ring=R, window=int(window), bq=pack_align)
         ctx = ctx.reshape(T, n_heads * d_head)

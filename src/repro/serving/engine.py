@@ -228,6 +228,8 @@ class ServingEngine:
     decode_slot_ticks = counter_attr("serving_decode_slot_ticks_total")
     prefill_launches = counter_attr("serving_prefill_launches_total")
     prefill_rows_executed = counter_attr("serving_prefill_rows_total")
+    prefill_history_pages = counter_attr(
+        "serving_prefill_history_pages_total")
     kv_resident_peak = gauge_attr("serving_kv_resident_peak_bytes")
     _n_ticks = counter_attr("serving_ticks_total")
     _n_prefill_ticks = counter_attr("serving_prefill_ticks_total")
@@ -413,14 +415,17 @@ class ServingEngine:
         # segment alignment of the packed stream: where the Pallas packed
         # prefill kernel runs on the page pool (TPU, float KV, no softcap)
         # it is the kernel's query tile, so the default paged path reaches
-        # the kernel.  The dense arena keeps the jnp path: its whole ring
-        # would be one kernel block, unchecked against VMEM.
+        # the kernel.  The dense arena keeps the user's alignment (the
+        # kernel serves it only where that is a multiple of the tile).
         tile = packed_kernel_align(
             cfg.attn.logit_softcap, quantized=sc.kv_dtype != "f32") \
             if sc.paged and not cfg.mla.enabled else 0
         self._pack_align = max(sc.phase.pack_align, tile)
         self.prefill_launches = 0        # prefill phase-program calls
         self.prefill_rows_executed = 0   # token rows computed (incl. pad)
+        # live history pages the packed paged prefill walks: per segment
+        # and attention run, the pages of min(offset, ring) cached tokens
+        self.prefill_history_pages = 0
         # the EXECUTOR owns the jitted program table, compile accounting
         # and device placement (serving/executor.py); the engine stays
         # host-only and reaches it through _program/_note_compile below
@@ -1066,6 +1071,10 @@ class ServingEngine:
                      key=lambda r: r.req_id)
         if victim is not oldest:
             self._preempt(victim)
+            # the victim's prefix pages may now be held by the cache alone:
+            # free one, or the next admission re-attaches them all and the
+            # stall repeats every tick
+            self._reclaim_cache(1)
 
     # -- tiered KV (host spill) --------------------------------------------------
     def _read_page(self, r: int, page: int) -> Dict[str, np.ndarray]:
@@ -1493,6 +1502,9 @@ class ServingEngine:
         head = (jnp.asarray(tokens), jnp.asarray(starts), jnp.asarray(offs),
                 jnp.asarray(lens), jnp.asarray(slots))
         if self.paged:
+            self.prefill_history_pages += sum(
+                p.pages_of(req.prefill_pos)
+                for req, _ in chunks for p in self.pool.pools)
             return _Call("packed_paged", (T, Nb), head,
                          (self.pool.block_tables(), *pp), all_greedy)
         return _Call("packed", (T, Nb), head, pp, all_greedy)

@@ -268,62 +268,173 @@ def test_compile_counter_stability():
 
 # -- kernel vs reference ----------------------------------------------------------
 
-def test_packed_kernel_matches_reference():
+# (G, P, ring, window, bk, n_pages, segments); a segment is (take, offset,
+# its block-table pages), and every stream ends in an all-sentinel pad
+# segment.  bk = 16 over 8-token pages makes a history block 2 pages.
+KERNEL_CASES = {
+    # a multi-segment stream over a wrapped SWA ring (the ring holds the
+    # last 16 positions; pages past the ring span are never read)
+    "swa_ring_wrapped": (2, 8, 16, 16, 32, 16,
+                         [(6, 21, [2, 3, 4, 5]), (11, 18, [7, 8, 9, 10])]),
+    "g1_history_not_page_aligned": (1, 8, 128, 0, 16, 16,
+                                    [(11, 21, [3, 9, 4])]),
+    "g4_history_over_several_blocks": (4, 8, 128, 0, 16, 16,
+                                       [(13, 45, [6, 1, 12, 0, 7, 3])]),
+    "g2_zero_history": (2, 8, 128, 0, 16, 16, [(20, 0, [])]),
+    "three_segments_three_histories": (
+        2, 8, 128, 0, 16, 24,
+        [(9, 0, []), (17, 13, [5, 11]), (7, 37, [2, 8, 14, 20, 1])]),
+    # a prefix-cache hit: both tables start with the same physical pages
+    "shared_prefix_pages": (2, 8, 128, 0, 16, 16,
+                            [(10, 16, [4, 5]), (6, 27, [4, 5, 9, 2])]),
+    # an unallocated (sentinel) page inside the live history is masked
+    "sentinel_page_in_history": (2, 8, 128, 0, 16, 16,
+                                 [(9, 21, [3, 16, 4])]),
+}
+
+
+def _kernel_case(G, P, n_pages, segs, bq, seed=1):
+    """Inputs of one packed stream: q/k/v, the pool, per-segment block
+    tables (sentinel past each segment's pages), and segment metadata."""
+    rng = np.random.default_rng(seed)
+    Hkv, D = 2, 16
+    segs = list(segs) + [(0, 0, [])]            # all-sentinel pad segment
+    starts, cur = [], 0
+    for t, _, _ in segs:
+        starts.append(cur)
+        cur = align_up(cur + t, bq)
+    T = max(cur, bq)
+    starts = np.array(starts, np.int32)
+    starts[-1] = T                              # pad segment: empty tail
+    offs = np.array([o for _, o, _ in segs], np.int32)
+    lens = np.array([t for t, _, _ in segs], np.int32)
+    W = max(max(len(pg) for _, _, pg in segs), 1)
+    bt = np.full((len(segs), W), n_pages, np.int32)
+    for i, (_, _, pgs) in enumerate(segs):
+        bt[i, :len(pgs)] = pgs
+    f = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    return dict(q=f(T, Hkv * G, D), kn=f(T, Hkv, D), vn=f(T, Hkv, D),
+                kp=f(n_pages, P, Hkv, D), vp=f(n_pages, P, Hkv, D), bt=bt,
+                starts=starts, offs=offs, lens=lens, T=T, Hkv=Hkv, D=D)
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_packed_kernel_matches_reference(case):
     """The Pallas packed-prefill kernel (interpret mode) reproduces the
-    pure-JAX packed reference on a multi-segment stream with arena
-    history, a wrapped SWA ring, and an all-sentinel pad segment."""
+    pure-JAX packed reference, and walks only live history: the same
+    inputs give bitwise-equal output with the block tables cut to their
+    live columns, and with those sentinel-padded to 64x that width."""
     from repro.kernels.flash_attention import packed_prefill_attention
     from repro.models.attention import _packed_attention_jax, \
         make_packed_segs
 
-    rng = np.random.default_rng(1)
-    Hkv, G, D, P, W, n_pages = 2, 2, 16, 8, 4, 16
-    ring, window, bq = 16, 16, 8
-    H = Hkv * G
-    segs = [(6, 21, [2, 3, 4, 5]), (11, 18, [7, 8, 9, 10]), (0, 0, [])]
-    takes = [t for t, _, _ in segs]
-    starts, cur = [], 0
-    for t in takes:
-        starts.append(cur)
-        cur = align_up(cur + t, bq)
-    T = max(cur, bq)
-    offs = np.array([o for _, o, _ in segs], np.int32)
-    lens = np.array(takes, np.int32)
-    starts = np.array(starts, np.int32)
-    starts[-1] = T                              # pad segment: empty tail
-    bt = np.full((len(segs), W), n_pages, np.int32)
-    for i, (_, _, pgs) in enumerate(segs):
-        bt[i, :len(pgs)] = pgs
-    q = rng.standard_normal((T, H, D)).astype(np.float32)
-    kn = rng.standard_normal((T, Hkv, D)).astype(np.float32)
-    vn = rng.standard_normal((T, Hkv, D)).astype(np.float32)
-    kp = rng.standard_normal((n_pages, P, Hkv, D)).astype(np.float32)
-    vp = rng.standard_normal((n_pages, P, Hkv, D)).astype(np.float32)
+    G, P, ring, window, bk, n_pages, segs = KERNEL_CASES[case]
+    bq = 8
+    c = _kernel_case(G, P, n_pages, segs, bq)
+    T, Hkv, D, bt = c["T"], c["Hkv"], c["D"], c["bt"]
+    H, N, W = Hkv * G, bt.shape[0], bt.shape[1]
 
-    out = packed_prefill_attention(
-        jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn), jnp.asarray(kp),
-        jnp.asarray(vp), jnp.asarray(bt), jnp.asarray(starts),
-        jnp.asarray(offs), jnp.asarray(lens), ring=ring, window=window,
-        bq=bq, interpret=True)
+    def run(table):
+        return np.asarray(packed_prefill_attention(
+            *(jnp.asarray(c[k]) for k in ("q", "kn", "vn", "kp", "vp")),
+            jnp.asarray(table), jnp.asarray(c["starts"]),
+            jnp.asarray(c["offs"]), jnp.asarray(c["lens"]), ring=ring,
+            window=window, bq=bq, bk=bk, interpret=True))
 
-    seg = make_packed_segs(starts, offs, lens,
-                           np.arange(len(segs), dtype=np.int32), T)
+    out = run(bt)
+    live = max(max(-(-min(o, ring) // P) for o in c["offs"]), 1)
+    wide = np.full((N, 64 * live), n_pages, np.int32)
+    wide[:, :live] = bt[:, :live]
+    np.testing.assert_array_equal(run(bt[:, :live]), out)
+    np.testing.assert_array_equal(run(wide), out)
+
+    seg = make_packed_segs(c["starts"], c["offs"], c["lens"],
+                           np.arange(N, dtype=np.int32), T)
     S = W * P
     pages = np.clip(bt, 0, n_pages - 1)
-    prev_k = jnp.asarray(kp)[pages].reshape(len(segs), S, Hkv, D)
-    prev_v = jnp.asarray(vp)[pages].reshape(len(segs), S, Hkv, D)
+    prev_k = jnp.asarray(c["kp"])[pages].reshape(N, S, Hkv, D)
+    prev_v = jnp.asarray(c["vp"])[pages].reshape(N, S, Hkv, D)
+    offs = c["offs"]
     s_idx = np.arange(S, dtype=np.int32)
     prev_pos = offs[:, None] - 1 - ((offs[:, None] - 1 - s_idx) % ring)
     prev_pos = np.where(s_idx[None, :] < ring, prev_pos, -1)
     prev_pos = np.where(np.repeat(bt >= n_pages, P, axis=1), -1, prev_pos)
     ref = _packed_attention_jax(
-        jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn), prev_k, prev_v,
-        jnp.asarray(prev_pos), seg, n_heads=H, n_kv_heads=Hkv, d_head=D,
-        window=jnp.int32(window), softcap=0.0).reshape(T, H, D)
+        jnp.asarray(c["q"]), jnp.asarray(c["kn"]), jnp.asarray(c["vn"]),
+        prev_k, prev_v, jnp.asarray(prev_pos), seg, n_heads=H,
+        n_kv_heads=Hkv, d_head=D, window=jnp.int32(window),
+        softcap=0.0).reshape(T, H, D)
 
     valid = np.asarray(seg.valid)
-    np.testing.assert_allclose(np.asarray(out)[valid],
-                               np.asarray(ref)[valid], atol=2e-5)
+    np.testing.assert_allclose(out[valid], np.asarray(ref)[valid], atol=2e-5)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_kernel_route_matches_jnp_route(monkeypatch, paged):
+    """forward_chunk_packed through the Pallas kernel (interpret mode, with
+    the backend reading "tpu" so the route is taken) gives the jnp route's
+    logits and KV, over the page pool and over the dense arena presented
+    as a pool of ring pages."""
+    from repro.kernels import ops
+    from repro.models import attention
+
+    cfg = tiny_cfg("qwen3-8b")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (150, 40)]
+    slots = [1, 3]
+    ticks = [[(0, 0, 128), (1, 0, 40)], [(0, 128, 22)]]
+
+    def run():
+        if paged:
+            pool = KVPool(cfg, n_slots=4, page_size=32, n_pages=16)
+            for i, p in enumerate(prompts):
+                assert pool.grow(slots[i], len(p))
+            cache = pool.caches
+        else:
+            pool, cache = None, init_cache(cfg, 4, 256)
+        return _run_ticks_packed(cfg, params, prompts, slots, ticks, cache,
+                                 pool, align=128)
+
+    ref, ref_cache = run()
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_interpret", lambda: True)
+    attention.reset_attention_route_counts()
+    got, got_cache = run()
+    assert attention.attention_route_counts().get(
+        "packed_prefill.kernel", 0) > 0
+    for k in ref:
+        np.testing.assert_allclose(got[k], ref[k], atol=1e-4, rtol=1e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(ref_cache),
+                    jax.tree_util.tree_leaves(got_cache)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_prefill_history_pages_counts_the_live_walk():
+    """``prefill_history_pages`` adds, for each packed paged prefill
+    chunk, the pages of the history it attends over: ceil(offset / page)
+    with the offset counted from the prefix-cache hit."""
+    cfg = tiny_cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(11)
+    head = rng.integers(0, cfg.vocab_size, size=20).astype(np.int32)
+    long = np.concatenate(
+        [head, rng.integers(0, cfg.vocab_size, size=5).astype(np.int32)])
+    eng = _engine(cfg, params, True, paged=True, prefix_cache=True)
+    r0 = eng.submit(head, max_new_tokens=2)
+    eng.run_until_drained(max_ticks=200)
+    r1 = eng.submit(long, max_new_tokens=2)
+    eng.run_until_drained(max_ticks=200)
+    # chunks of 8 over 8-token pages; the head published 2 full pages
+    assert (r0.cached_tokens, r1.cached_tokens) == (0, 16)
+    want = sum(-(-off // 8) for r in (r0, r1)
+               for off in range(r.cached_tokens, r.prompt_len, 8))
+    assert want == (0 + 1 + 2) + (2 + 3)
+    assert eng.prefill_history_pages == want
+    snap = eng.metrics.snapshot()
+    assert snap["counters"]["serving_prefill_history_pages_total"] == want
 
 
 # -- kernel routes and the kernel's segment alignment ------------------------------

@@ -587,7 +587,7 @@ except ImportError:                       # pragma: no cover
     HAVE_HYPOTHESIS = False
 
 if HAVE_HYPOTHESIS:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 
     @settings(max_examples=25, deadline=None)
     @given(ops=st.lists(
@@ -596,6 +596,9 @@ if HAVE_HYPOTHESIS:
                   st.integers(0, 7),      # prompt selector / abort target
                   st.integers(1, 30)),    # prompt length
         max_size=30))
+    # two same-prefix prompts too long to prefill side by side: the stall
+    # breaker's victim must not re-attach its cached pages every tick
+    @example(ops=[(0, 0, 25), (0, 0, 24)])
     def test_abort_interleavings_conserve_refcounts(ops):
         """ANY interleaving of submit / step / abort on a small paged
         pool with the prefix cache and n-gram speculation on (so

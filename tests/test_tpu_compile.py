@@ -2,12 +2,13 @@
 
 Interpret mode (every other kernel test) runs the kernel body as JAX ops
 and never meets Mosaic's tiling rules; these tests hand the real TPU
-compiler each main-path kernel at qwen3-1.7b widths (H=16, Hkv=8, D=128,
-page 16, bf16) for a DESCRIBED v5e chip — nothing runs, no chip needed.
-The topology is described inside a fixture (never at import: only one
-process may load the TPU library, and pytest-xdist workers all import
-this file), and the persistent compilation cache is off around the
-compiles (an entry compiled for a described chip cannot be read back).
+compiler each main-path kernel at the served widths (qwen3-1.7b: H=16,
+Hkv=8; qwen3-8b: H=32; D=128, page 16, bf16) for a DESCRIBED v5e chip —
+nothing runs, no chip needed.  The topology is described inside a
+fixture (never at import: only one process may load the TPU library, and
+pytest-xdist workers all import this file), and the persistent
+compilation cache is off around the compiles (an entry compiled for a
+described chip cannot be read back).
 """
 
 from __future__ import annotations
@@ -58,18 +59,26 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-def test_packed_prefill_attention_compiles(one_chip, no_persistent_cache):
-    """The served prefill kernel: a 2,048-token packed stream of 8
-    segments over a 2,048-page pool (block-table width = the pool)."""
-    T, n_pages, n_seg = 2048, 2048, 8
-    _compile(
+@pytest.mark.parametrize("T,n_pages,h,hkv", [
+    (2048, 2560, 32, 8),     # qwen3-8b-l12.halo-longctx
+    (256, 1536, 16, 8),      # qwen3-1.7b.chat-shared: a user turn
+    (2048, 1536, 16, 8),     # qwen3-1.7b.chat-shared: a full chunk
+])
+def test_packed_prefill_attention_compiles(one_chip, no_persistent_cache,
+                                           T, n_pages, h, hkv):
+    """The served prefill kernel at the benchmark's two shapes: a packed
+    stream of 8 segments over the cell's pool (block-table width = the
+    pool), under its stable trace name."""
+    n_seg = 8
+    compiled = _compile(
         lambda *a: fa.packed_prefill_attention(*a, ring=n_pages * PAGE,
                                                bq=128),
         one_chip,
-        ((T, H, D), BF16), ((T, HKV, D), BF16), ((T, HKV, D), BF16),
-        ((n_pages, PAGE, HKV, D), BF16), ((n_pages, PAGE, HKV, D), BF16),
+        ((T, h, D), BF16), ((T, hkv, D), BF16), ((T, hkv, D), BF16),
+        ((n_pages, PAGE, hkv, D), BF16), ((n_pages, PAGE, hkv, D), BF16),
         ((n_seg, n_pages), I32), ((n_seg,), I32), ((n_seg,), I32),
         ((n_seg,), I32))
+    assert "packed_prefill_attention" in compiled.as_text()
 
 
 def test_paged_decode_attention_compiles(one_chip, no_persistent_cache):
